@@ -311,6 +311,8 @@ def table11_controller_frontier(requests=4, lanes=2, steps=12,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     table4_decay()
     table5_threshold()
     table6_verify_layer()

@@ -140,6 +140,8 @@ def trajectory_analysis(batch=4):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fig2_quality_curve()
     fig6_layer_correlation()
     trajectory_analysis()

@@ -38,4 +38,6 @@ def run(batch: int = 16, methods=None, seed: int = 3):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

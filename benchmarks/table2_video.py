@@ -61,4 +61,6 @@ def run(batch: int = 8, methods=None, seed: int = 5,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -45,4 +45,6 @@ def run(batch: int = 16, methods=None, seed: int = 7):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -50,6 +50,7 @@ lane's slice; harvesting reads back the emitted token row.
 """
 from __future__ import annotations
 
+import copy
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -152,6 +153,15 @@ class Workload:
                 # is an exact copy on every backend
                 out[k] = _gather_rollback(v, n_acc, ax)
         return out
+
+    def with_params(self, params) -> "Workload":
+        """A shallow copy of this adapter serving ``params``. The engine
+        traces its lane step through one whose params are the step's
+        argument, so the weights enter the compiled program as an input
+        rather than as constants embedded in it."""
+        wl = copy.copy(self)
+        wl.params = params
+        return wl
 
     def select_dyn(self, mask, new, cur):
         return {k: _axis_where(mask, self.dyn_axes[k], new[k], v)
@@ -310,10 +320,11 @@ class DecodeWorkload(Workload):
         self.full_flops = decode_forward_flops(cfg, self.max_seq_len)
         self.verify_flops = decode_verify_flops(cfg, self.max_seq_len)
         self._cmask = jnp.arange(cfg.num_layers) == self.verify_layer
+        # params are an argument, not constants embedded in the program
         self._prefill = jax.jit(self._prefill_impl)
 
-    def _prefill_impl(self, tokens):
-        logits, extras = M.lm_forward(self.cfg, self.params,
+    def _prefill_impl(self, params, tokens):
+        logits, extras = M.lm_forward(self.cfg, params,
                                       {"tokens": tokens},
                                       collect_cache=True)
         return logits[:, -1], extras["cache"]
@@ -403,7 +414,7 @@ class DecodeWorkload(Workload):
     def fill_payload(self, state, lane, request, steps):
         prompt = self._prompt_of(request, steps)
         P = prompt.shape[1]
-        logits, cache = self._prefill(jnp.asarray(prompt))
+        logits, cache = self._prefill(self.params, jnp.asarray(prompt))
         tok0 = int(np.argmax(np.asarray(jax.device_get(logits))[0]))
         state = dict(state)
         for key in self._cache_keys:

@@ -1,9 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as traced Python for correctness validation; on a TPU
-backend the same calls compile to Mosaic. ``REPRO_FORCE_INTERPRET=0`` can
-force compiled mode for real-TPU runs.
+Where the kernels run is chosen by the backend JAX reports: on ``tpu``
+every call compiles to a Mosaic kernel (a ``tpu_custom_call`` in the
+HLO); on ``cpu`` the same calls run in ``interpret=True`` mode — the
+kernel body executes as traced jnp, which is how the test suite checks
+them without a chip. Any other backend is an error rather than a silent
+fallback.
 
 Exported surface (each documented on its function):
 
@@ -30,7 +32,6 @@ Exported surface (each documented on its function):
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -43,10 +44,15 @@ from repro.kernels import ref as ref  # noqa: F401 (re-export for tests)
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    """True on the CPU (Pallas interpreter), False on a TPU (Mosaic)."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels compile for 'tpu' and are "
+                       f"interpreted on 'cpu'; backend {backend!r} is "
+                       "neither")
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -100,6 +106,24 @@ def taylor_update(old_diffs: jnp.ndarray, feats: jnp.ndarray, *,
     return out.reshape(m1, -1)[:, :n].reshape((m1,) + shape)
 
 
+def _lane_cols(C: int, block_c: int):
+    """(padded C, column tile) for a lane kernel: C pads to a multiple of
+    ``_tp.SUBLANES`` and the folded row width S = C/SUBLANES is tiled by
+    the largest power-of-two share of ``block_c`` that divides it (a
+    multiple of 128), or taken whole when S is not a 128-multiple and
+    fits one tile; only a wider, unaligned S pads up to the 128 tile."""
+    sub = _tp.SUBLANES
+    S = -(-C // sub)
+    if S % 128 and S > block_c:
+        S = -(-S // 128) * 128
+    if S % 128:
+        return S * sub, S
+    bc = min(block_c, S)
+    while S % bc:
+        bc //= 2
+    return S * sub, bc
+
+
 def _lane_fold(shape, lane_axis: int):
     """(G, B, C) row/lane/col factorisation of a feature layout."""
     B = shape[lane_axis]
@@ -127,11 +151,8 @@ def taylor_predict_lanes(diffs: jnp.ndarray, weights: jnp.ndarray, *,
     m1 = diffs.shape[0]
     feat = diffs.shape[1:]
     G, B, C = _lane_fold(feat, lane_axis)
-    flat = _pad_to(diffs.reshape(m1, G * B, C), 2, 128)
-    cp = flat.shape[2]
-    bc = min(block_c, cp)
-    while cp % bc:
-        bc //= 2
+    cp, bc = _lane_cols(C, block_c)
+    flat = _pad_to(diffs.reshape(m1, G * B, C), 2, cp)
     out = _tp.taylor_predict_lanes_2d(flat, weights, lanes=B, block_c=bc,
                                       interpret=_interpret())
     return out[:, :C].reshape(feat)
@@ -152,11 +173,8 @@ def taylor_predict_chain_lanes(diffs: jnp.ndarray, weights: jnp.ndarray, *,
     m1, K = weights.shape[0], weights.shape[1]
     feat = diffs.shape[1:]
     G, B, C = _lane_fold(feat, lane_axis)
-    flat = _pad_to(diffs.reshape(m1, G * B, C), 2, 128)
-    cp = flat.shape[2]
-    bc = min(block_c, cp)
-    while cp % bc:
-        bc //= 2
+    cp, bc = _lane_cols(C, block_c)
+    flat = _pad_to(diffs.reshape(m1, G * B, C), 2, cp)
     out = _tp.taylor_predict_chain_2d(flat, weights, lanes=B, block_c=bc,
                                       interpret=_interpret())
     return out[:, :, :C].reshape((K,) + feat)
@@ -177,12 +195,9 @@ def lane_rollback(chain: jnp.ndarray, idx: jnp.ndarray, *,
     K1 = chain.shape[0]
     feat = chain.shape[1:]
     G, B, C = _lane_fold(feat, lane_axis)
-    flat = _pad_to(chain.reshape(K1, G * B, C), 2, 128)
-    cp = flat.shape[2]
-    bc = min(block_c, cp)
-    while cp % bc:
-        bc //= 2
-    out = _tp.lane_rollback_2d(flat, jnp.asarray(idx, jnp.float32),
+    cp, bc = _lane_cols(C, block_c)
+    flat = _pad_to(chain.reshape(K1, G * B, C), 2, cp)
+    out = _tp.lane_rollback_2d(flat, jnp.asarray(idx, jnp.int32),
                                lanes=B, block_c=bc,
                                interpret=_interpret())
     return out[:, :C].reshape(feat)
@@ -201,12 +216,9 @@ def taylor_update_lanes(old_diffs: jnp.ndarray, feats: jnp.ndarray,
     m1 = old_diffs.shape[0]
     feat = old_diffs.shape[1:]
     G, B, C = _lane_fold(feat, lane_axis)
-    od = _pad_to(old_diffs.reshape(m1, G * B, C), 2, 128)
-    f = _pad_to(feats.astype(old_diffs.dtype).reshape(G * B, C), 1, 128)
-    cp = od.shape[2]
-    bc = min(block_c, cp)
-    while cp % bc:
-        bc //= 2
+    cp, bc = _lane_cols(C, block_c)
+    od = _pad_to(old_diffs.reshape(m1, G * B, C), 2, cp)
+    f = _pad_to(feats.astype(old_diffs.dtype).reshape(G * B, C), 1, cp)
     out = _tp.taylor_update_lanes_2d(od, f, mask, lanes=B, block_c=bc,
                                      interpret=_interpret())
     return out[:, :, :C].reshape((m1,) + feat)
@@ -228,12 +240,9 @@ def spectral_update_lanes(old_ring: jnp.ndarray, feats: jnp.ndarray,
     m1 = old_ring.shape[0]
     feat = old_ring.shape[1:]
     G, B, C = _lane_fold(feat, lane_axis)
-    od = _pad_to(old_ring.reshape(m1, G * B, C), 2, 128)
-    f = _pad_to(feats.astype(old_ring.dtype).reshape(G * B, C), 1, 128)
-    cp = od.shape[2]
-    bc = min(block_c, cp)
-    while cp % bc:
-        bc //= 2
+    cp, bc = _lane_cols(C, block_c)
+    od = _pad_to(old_ring.reshape(m1, G * B, C), 2, cp)
+    f = _pad_to(feats.astype(old_ring.dtype).reshape(G * B, C), 1, cp)
     out = _sp.spectral_update_lanes_2d(od, f, mask, lanes=B, block_c=bc,
                                        interpret=_interpret())
     return out[:, :, :C].reshape((m1,) + feat)
@@ -399,13 +408,12 @@ def verify_accept_pairs(pred: jnp.ndarray, ref_: jnp.ndarray,
 # lane block (the kernels are per-lane-independent, so local == global per
 # lane, bit-for-bit), and the lane axis never leaves its device. The jnp
 # table path needs no wrapper — einsum/where partition natively and serve
-# as the sharded oracle. ``check_rep=False`` because the custom call
-# defeats shard_map's replication checker.
+# as the sharded oracle. ``check_vma=False`` because the custom call
+# defeats shard_map's varying-manual-axes checker.
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _lane_p(ndim: int, lane_dim: int, axis: str):

@@ -31,18 +31,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.taylor_predict import (
+    SUBLANES, _SMEM, _fold, _lane_grid, _row_spec,
     taylor_predict_chain_2d as spectral_predict_chain_2d,  # noqa: F401
     taylor_predict_lanes_2d as spectral_predict_lanes_2d,  # noqa: F401
 )
 
 
 def _ring_update_kernel(m_ref, d_ref, f_ref, o_ref, *, order: int):
-    # m_ref block is this lane's refresh mask as a [1, 1] f32 plane;
-    # d_ref holds the m+1 ring rows of one (1, block_c) row-tile; f_ref
-    # is the new anchor features tile.  Refreshing lanes shift their
-    # ring (row 0 <- feats, row i <- old row i-1); untouched lanes copy
-    # through.  Exact copies in the table dtype — bitwise.
-    refresh = m_ref[0, 0] > 0.0
+    # m_ref is the whole [lanes] refresh mask in SMEM, read at this lane's
+    # grid index; d_ref holds the m+1 ring rows of one (16, block_c)
+    # tile; f_ref is the new anchor features tile. Refreshing lanes shift
+    # their ring (row 0 <- feats, row i <- old row i-1); untouched lanes
+    # copy through. Exact copies in the table dtype — bitwise.
+    refresh = m_ref[pl.program_id(1)] != 0
     o_ref[0] = jnp.where(refresh, f_ref[...].astype(o_ref.dtype), d_ref[0])
     for i in range(1, order + 1):
         o_ref[i] = jnp.where(refresh, d_ref[i - 1], d_ref[i])
@@ -54,30 +55,22 @@ def spectral_update_lanes_2d(old_ring: jnp.ndarray, feats: jnp.ndarray,
                              interpret: bool = False) -> jnp.ndarray:
     """Masked per-lane ring-shift refresh of the raw-anchor table.
 
-    old_ring [m+1, R, C] (R = G·lanes, lane = row % lanes), feats [R, C]
-    (the new anchor features in the same layout), mask [lanes] (nonzero
-    = refresh that lane) -> new ring [m+1, R, C].  Single pass over the
-    table; no whole-table temporary.
+    old_ring [m+1, R, C] (R = G·lanes, lane = row % lanes, C % 16 == 0),
+    feats [R, C] (the new anchor features in the same layout), mask
+    [lanes] (nonzero = refresh that lane) -> new ring [m+1, R, C].
+    Single pass over the table; no whole-table temporary.
     """
     m1, R, C = old_ring.shape
-    assert R % lanes == 0 and feats.shape == (R, C)
-    block_c = min(block_c, C)
-    assert C % block_c == 0, (C, block_c)
-    G = R // lanes
-    grid = (G, lanes, C // block_c)
-    # mask travels as a [lanes, 1] f32 plane so its block stays 2-D like
-    # every other VMEM operand (rank-1 blocks are a Mosaic lowering hazard)
-    return pl.pallas_call(
+    assert feats.shape == (R, C), (feats.shape, R, C)
+    grid = _lane_grid(R, C, lanes, block_c)
+    out = pl.pallas_call(
         functools.partial(_ring_update_kernel, order=m1 - 1),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda g, b, c: (b, 0)),
-            pl.BlockSpec((m1, 1, block_c),
-                         lambda g, b, c: (0, g * lanes + b, c)),
-            pl.BlockSpec((1, block_c), lambda g, b, c: (g * lanes + b, c)),
-        ],
-        out_specs=pl.BlockSpec((m1, 1, block_c),
-                               lambda g, b, c: (0, g * lanes + b, c)),
-        out_shape=jax.ShapeDtypeStruct((m1, R, C), old_ring.dtype),
+        in_specs=[_SMEM, _row_spec(lanes, block_c, m1),
+                  _row_spec(lanes, block_c)],
+        out_specs=_row_spec(lanes, block_c, m1),
+        out_shape=jax.ShapeDtypeStruct((m1, R, SUBLANES, C // SUBLANES),
+                                       old_ring.dtype),
         interpret=interpret,
-    )(mask.astype(jnp.float32).reshape(lanes, 1), old_ring, feats)
+    )(jnp.asarray(mask).astype(jnp.int32), _fold(old_ring), _fold(feats))
+    return out.reshape(m1, R, C)

@@ -189,6 +189,8 @@ def main() -> None:
     # serve functions for exactly this reason)
     from repro.launch.mesh import force_host_device_count
     force_host_device_count(args.mesh)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "diffusion":
         serve_diffusion(args)
     else:

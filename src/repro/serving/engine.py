@@ -72,6 +72,7 @@ request completes (its sample must be read anyway).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Iterator, List, Optional, Tuple, Union
 
@@ -709,6 +710,14 @@ class SpeCaEngine:
             raise ValueError("engine needs at least one workload: pass "
                              "the diffusion (cfg, params, dcfg, scfg) "
                              "quartet and/or workloads={...}")
+        if mesh is not None:
+            # the weights replicate over the lane mesh once, here, rather
+            # than on every call of a sharded step
+            from repro.sharding.specs import replicated
+            self.workloads = {
+                tag: wl.with_params(jax.device_put(wl.params,
+                                                   replicated(mesh)))
+                for tag, wl in self.workloads.items()}
         diff = self.workloads.get("diffusion")
         self.stepper = getattr(diff, "stepper", None)
         self.vl = diff.verify_layer if diff is not None else None
@@ -831,16 +840,27 @@ class SpeCaEngine:
                    tag: str = "diffusion"):
         """The jitted W-lane step (compiled once per workload × width ×
         program): ``mode=False`` is the plain per-lane program,
-        ``"mixed"`` the slot-width pair-mask program."""
+        ``"mixed"`` the slot-width pair-mask program. Returned as
+        ``partial(jitted, params)``: call it with the lane state alone,
+        or AOT-compile ``.func`` on ``(*.args, state)``."""
         key = (tag, W, mode)
         if key not in self._lane_fns:
-            self._lane_fns[key] = jax.jit(LS.build_workload_step(
-                self._workload(tag), lanes=W,
-                draft_mode=self.draft_mode, accept_mode=self.accept_mode,
-                verify_backend=self.verify_backend,
-                guidance=mode, max_draft_depth=self.max_draft_depth,
-                forecaster=self.forecaster, controller=self.controller,
-                mesh=self.mesh))
+            wl = self._workload(tag)
+
+            def run(params, state):
+                # the weights are the program's argument: traced through
+                # a params-swapped adapter, never embedded as constants
+                return LS.build_workload_step(
+                    wl.with_params(params), lanes=W,
+                    draft_mode=self.draft_mode,
+                    accept_mode=self.accept_mode,
+                    verify_backend=self.verify_backend,
+                    guidance=mode, max_draft_depth=self.max_draft_depth,
+                    forecaster=self.forecaster,
+                    controller=self.controller, mesh=self.mesh)(state)
+
+            self._lane_fns[key] = functools.partial(jax.jit(run),
+                                                    wl.params)
             if self._obs is not None:
                 # per-tag program-build count (the compile-cost proxy:
                 # each new (tag, width, mode) key is one XLA program)

@@ -123,18 +123,30 @@ def test_taylor_lanes_bf16_table_quantisation_bounded():
 
 
 def test_predict_lanes_degenerate_equals_scalar_kernel():
-    """Identical weight columns make the lane kernel the scalar kernel:
-    per-element FMA order is the same, so the results are bit-equal —
-    the invariant that lets the sampler treat whole-batch anchors as the
-    lanes=B degenerate case."""
+    """Identical weight columns make the lane kernel the whole-batch
+    forecast: with every lane on the same column it must agree with the
+    scalar-weight oracle and ``core.taylor.predict`` to float32 rounding
+    — the invariant that lets the sampler treat whole-batch anchors as
+    the lanes=B degenerate case."""
+    from repro.core import taylor as T
     key = jax.random.PRNGKey(0)
     feat = (2, 2, 3, 12, 24)
     diffs = jax.random.normal(key, (3,) + feat, jnp.float32)
     w = jax.random.normal(jax.random.fold_in(key, 1), (3,))
     wl = jnp.broadcast_to(w[:, None], (3, feat[2]))
-    got = ops.taylor_predict_lanes(diffs, wl, lane_axis=2)
-    want = ops.taylor_predict(diffs, w)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    got = np.asarray(ops.taylor_predict_lanes(diffs, wl, lane_axis=2))
+    np.testing.assert_allclose(got, np.asarray(R.taylor_predict_ref(diffs, w)),
+                               **_tol(jnp.float32))
+    # the same weights through the core forecast: a state whose anchor
+    # metadata yields w at step d reproduces the kernel's prediction
+    state = {"diffs": diffs, "n_anchors": jnp.asarray(3, jnp.int32),
+             "anchor_step": jnp.asarray(0, jnp.int32),
+             "gap": jnp.asarray(2.0, jnp.float32)}
+    wt = T.prediction_weights(2, 3.0, 2.0, 3)
+    want = np.asarray(T.predict(state, 3))
+    got = np.asarray(ops.taylor_predict_lanes(
+        diffs, jnp.broadcast_to(wt[:, None], (3, feat[2])), lane_axis=2))
+    np.testing.assert_allclose(got, want, **_tol(jnp.float32))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
