@@ -1,0 +1,3 @@
+from bench.harness.readers import full_branch_share, for_family
+
+read = for_family(full_branch_share, "dit")

@@ -1,0 +1,3 @@
+from bench.harness.readers import lane_occupancy, for_family
+
+read = for_family(lane_occupancy, "dit")
