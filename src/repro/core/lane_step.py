@@ -46,6 +46,14 @@ One step, entirely inside the traced function:
      one-pass masked kernel; accepted lanes advance on the speculative
      output via a per-lane select.
 
+Each phase runs under a ``jax.named_scope``, which the compiled ops keep
+in their ``op_name`` metadata, so a device profile splits the step:
+``speca.draft`` (the forecast and the verify-layer forward),
+``speca.verify`` inside it (the verify-layer forward and the error
+check), ``speca.full`` (the full forward), ``speca.update`` inside it
+(the table refresh) and, for deep chains, ``speca.rollback`` (the
+snapshot stack and restore). Scopes change no value and no operation.
+
 State layout (all device-side; the host never has to read any of it to
 decide the next dispatch). Shared, workload-independent keys:
 
@@ -528,19 +536,22 @@ def build_workload_step(wl, *, lanes: int, draft_mode: str = "taylor",
                                  scfg.beta)                       # [W]
 
         def attempt(dyn):
-            preds = fc.predict_lanes(tstate, s_eff, mode=draft_mode,
-                                     mesh=mesh, order_cap=order_cap)
-            out, real_vl = wl.spec_forward(dyn, cond, ctx, preds)
-            pred_vl = preds[vl][0] + preds[vl][1]
-            if pairing:
-                err, ok = verify_mixed(pred_vl, real_vl, tau,
-                                       state["gscale"], state["paired"])
-            else:
-                err, ok = verify(pred_vl, real_vl, tau)
-            # NaN marks "did not draft": it cannot poison downstream
-            # means/percentiles the way the old inf sentinel did, and it
-            # still fails every `err <= tau` comparison.
-            return out, jnp.where(want, err, jnp.nan), ok & want
+            with jax.named_scope("speca.draft"):
+                preds = fc.predict_lanes(tstate, s_eff, mode=draft_mode,
+                                         mesh=mesh, order_cap=order_cap)
+                with jax.named_scope("speca.verify"):
+                    out, real_vl = wl.spec_forward(dyn, cond, ctx, preds)
+                    pred_vl = preds[vl][0] + preds[vl][1]
+                    if pairing:
+                        err, ok = verify_mixed(pred_vl, real_vl, tau,
+                                               state["gscale"],
+                                               state["paired"])
+                    else:
+                        err, ok = verify(pred_vl, real_vl, tau)
+                # NaN marks "did not draft": it cannot poison downstream
+                # means/percentiles the way the old inf sentinel did, and
+                # it still fails every `err <= tau` comparison.
+                return out, jnp.where(want, err, jnp.nan), ok & want
 
         def skip(dyn):
             return (wl.zero_out(W),
@@ -556,12 +567,14 @@ def build_workload_step(wl, *, lanes: int, draft_mode: str = "taylor",
         need_full = jnp.any(active & ~accept)
 
         def do_full(opers):
-            dyn, tstate = opers
-            out, branches = wl.full_forward(dyn, cond, ctx)
-            tstate = fc.update_lanes(tstate, branches,
-                                     s_eff, active & ~accept,
-                                     mesh=mesh)
-            return out, tstate
+            with jax.named_scope("speca.full"):
+                dyn, tstate = opers
+                out, branches = wl.full_forward(dyn, cond, ctx)
+                with jax.named_scope("speca.update"):
+                    tstate = fc.update_lanes(tstate, branches,
+                                             s_eff, active & ~accept,
+                                             mesh=mesh)
+                return out, tstate
 
         def keep(opers):
             dyn, tstate = opers
@@ -618,9 +631,10 @@ def build_workload_step(wl, *, lanes: int, draft_mode: str = "taylor",
         # there is exactly step₀ + j (clamped to the schedule end).
         steps_chain = jnp.minimum(
             s[None, :] + jnp.arange(K, dtype=jnp.int32)[:, None], S - 1)
-        preds_chain = fc.predict_chain_lanes(tstate, steps_chain,
-                                             mode=draft_mode, mesh=mesh,
-                                             order_cap=order_cap)
+        with jax.named_scope("speca.draft"):
+            preds_chain = fc.predict_chain_lanes(tstate, steps_chain,
+                                                 mode=draft_mode, mesh=mesh,
+                                                 order_cap=order_cap)
         alive = active
         stop_full = jnp.zeros((W,), bool)
         n_acc = jnp.zeros((W,), jnp.int32)
@@ -644,15 +658,17 @@ def build_workload_step(wl, *, lanes: int, draft_mode: str = "taylor",
             preds = preds_chain[j]
 
             def attempt(dyn, want=want, tau=tau, ctx=ctx, preds=preds):
-                out, real_vl = wl.spec_forward(dyn, cond, ctx, preds)
-                pred_vl = preds[vl][0] + preds[vl][1]
-                if pairing:
-                    err, ok = verify_mixed(pred_vl, real_vl, tau,
-                                           state["gscale"],
-                                           state["paired"])
-                else:
-                    err, ok = verify(pred_vl, real_vl, tau)
-                return out, jnp.where(want, err, jnp.nan), ok & want
+                with jax.named_scope("speca.draft"), \
+                        jax.named_scope("speca.verify"):
+                    out, real_vl = wl.spec_forward(dyn, cond, ctx, preds)
+                    pred_vl = preds[vl][0] + preds[vl][1]
+                    if pairing:
+                        err, ok = verify_mixed(pred_vl, real_vl, tau,
+                                               state["gscale"],
+                                               state["paired"])
+                    else:
+                        err, ok = verify(pred_vl, real_vl, tau)
+                    return out, jnp.where(want, err, jnp.nan), ok & want
 
             def skip(dyn):
                 return (wl.zero_out(W),
@@ -693,8 +709,10 @@ def build_workload_step(wl, *, lanes: int, draft_mode: str = "taylor",
         # rollback: per-lane exact-copy restore to the snapshot at the
         # lane's accepted-prefix length (inactive/rejected-at-0 lanes get
         # snapshot 0 — their pre-tick payload, bit-exactly)
-        chain = {k: jnp.stack([sn[k] for sn in snaps]) for k in wl.dyn_keys}
-        dyn = wl.rollback(chain, n_acc, mesh=mesh)
+        with jax.named_scope("speca.rollback"):
+            chain = {k: jnp.stack([sn[k] for sn in snaps])
+                     for k in wl.dyn_keys}
+            dyn = wl.rollback(chain, n_acc, mesh=mesh)
         # ONE closing full forward serves every rejected lane at its
         # rolled-back step and refreshes only those lanes' table slices
         s_eff = jnp.minimum(s, S - 1)
@@ -702,11 +720,13 @@ def build_workload_step(wl, *, lanes: int, draft_mode: str = "taylor",
         need_full = jnp.any(stop_full)
 
         def do_full(opers):
-            dyn, tstate = opers
-            out, branches = wl.full_forward(dyn, cond, ctx)
-            tstate = fc.update_lanes(tstate, branches,
-                                     s_eff, stop_full, mesh=mesh)
-            return out, tstate
+            with jax.named_scope("speca.full"):
+                dyn, tstate = opers
+                out, branches = wl.full_forward(dyn, cond, ctx)
+                with jax.named_scope("speca.update"):
+                    tstate = fc.update_lanes(tstate, branches,
+                                             s_eff, stop_full, mesh=mesh)
+                return out, tstate
 
         def keep(opers):
             dyn, tstate = opers

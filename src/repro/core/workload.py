@@ -67,6 +67,7 @@ from repro.core.lane_step import verify_layer as _verify_layer
 from repro.diffusion.pipeline import latent_shape, make_stepper, model_inputs
 from repro.layers import blocks as blk
 from repro.layers import model as M
+from repro.obs.trace import span
 
 
 def _axis_where(mask: jnp.ndarray, axis: int, a: jnp.ndarray,
@@ -269,7 +270,8 @@ class DiffusionWorkload(Workload):
         return state
 
     def emit(self, state, lane, done):
-        return jax.device_get(state["x"][lane:lane + 1])
+        with span("speca.sync.emit", lane=lane):
+            return jax.device_get(state["x"][lane:lane + 1])
 
 
 class DecodeWorkload(Workload):
@@ -415,12 +417,13 @@ class DecodeWorkload(Workload):
         prompt = self._prompt_of(request, steps)
         P = prompt.shape[1]
         logits, cache = self._prefill(self.params, jnp.asarray(prompt))
-        tok0 = int(np.argmax(np.asarray(jax.device_get(logits))[0]))
+        with span("speca.sync.prefill", lane=lane):
+            tok0 = int(np.argmax(np.asarray(jax.device_get(logits))[0]))
         state = dict(state)
         for key in self._cache_keys:
             # clear the lane's slice (previous occupant), then scatter the
-            # prefix — both lane-local updates the partitioner keeps on
-            # the owning shard
+            # prefix (on a mesh each eager scatter at a traced lane index
+            # gathers the whole lane-sharded leaf first)
             cleared = state[key].at[:, lane].set(0)
             if key in ("k", "v"):
                 state[key] = cleared.at[:, lane, :P].set(cache[key][:, 0])
@@ -432,7 +435,8 @@ class DecodeWorkload(Workload):
         return state
 
     def emit(self, state, lane, done):
-        toks = np.asarray(jax.device_get(state["tokens"][lane]))
+        with span("speca.sync.emit", lane=lane):
+            toks = np.asarray(jax.device_get(state["tokens"][lane]))
         return toks[:max(min(done, self.num_steps), 0)].copy()
 
 

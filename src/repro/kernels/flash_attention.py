@@ -81,6 +81,7 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         causal=causal, window=window, scale=scale)
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, q_, k_: (b, q_, 0)),

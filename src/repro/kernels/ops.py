@@ -288,7 +288,8 @@ def verify_accept(pred: jnp.ndarray, ref_: jnp.ndarray, tau: jnp.ndarray, *,
     while p.shape[1] % bc:
         bc //= 2
     out = _ve.verify_sums(p, r, tau=jnp.asarray(tau, jnp.float32), eps=eps,
-                          block_c=bc, interpret=_interpret())
+                          block_c=bc, interpret=_interpret(),
+                          name="verify_accept")
     return out[:, 2], out[:, 3] > 0.0
 
 
@@ -364,7 +365,8 @@ def verify_accept_mixed(pred: jnp.ndarray, ref_: jnp.ndarray,
     while p.shape[1] % bc:
         bc //= 2
     out = _ve.verify_sums(p, r, tau=jnp.asarray(tau, jnp.float32),
-                          eps=eps, block_c=bc, interpret=_interpret())
+                          eps=eps, block_c=bc, interpret=_interpret(),
+                          name="verify_accept_mixed")
     return out[:, 2], out[:, 3] > 0.0
 
 

@@ -65,6 +65,7 @@ def spectral_update_lanes_2d(old_ring: jnp.ndarray, feats: jnp.ndarray,
     grid = _lane_grid(R, C, lanes, block_c)
     out = pl.pallas_call(
         functools.partial(_ring_update_kernel, order=m1 - 1),
+        name="spectral_update_lanes",
         grid=grid,
         in_specs=[_SMEM, _row_spec(lanes, block_c, m1),
                   _row_spec(lanes, block_c)],

@@ -60,6 +60,7 @@ def taylor_predict_2d(diffs: jnp.ndarray, weights: jnp.ndarray, *,
     grid = (R // block_r, C // block_c)
     return pl.pallas_call(
         functools.partial(_predict_kernel, order=m1 - 1),
+        name="taylor_predict",
         grid=grid,
         in_specs=[
             pl.BlockSpec((m1,), lambda r, c: (0,)),
@@ -125,6 +126,7 @@ def taylor_predict_lanes_2d(diffs: jnp.ndarray, weights: jnp.ndarray, *,
     grid = _lane_grid(R, C, lanes, block_c)
     out = pl.pallas_call(
         functools.partial(_predict_lanes_kernel, order=m1 - 1),
+        name="taylor_predict_lanes",
         grid=grid,
         in_specs=[_SMEM, _row_spec(lanes, block_c, m1)],
         out_specs=_row_spec(lanes, block_c),
@@ -170,6 +172,7 @@ def taylor_predict_chain_2d(diffs: jnp.ndarray, weights: jnp.ndarray, *,
     grid = _lane_grid(R, C, lanes, block_c)
     out = pl.pallas_call(
         functools.partial(_predict_chain_kernel, order=m1 - 1, depth=K),
+        name="taylor_predict_chain_lanes",
         grid=grid,
         in_specs=[_SMEM, _row_spec(lanes, block_c, m1)],
         out_specs=_row_spec(lanes, block_c, K),
@@ -209,6 +212,7 @@ def lane_rollback_2d(chain: jnp.ndarray, idx: jnp.ndarray, *, lanes: int,
     idx = jnp.clip(jnp.asarray(idx, jnp.int32), 0, K1 - 1)
     out = pl.pallas_call(
         _lane_rollback_kernel,
+        name="lane_rollback",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -255,6 +259,7 @@ def taylor_update_lanes_2d(old_diffs: jnp.ndarray, feats: jnp.ndarray,
     grid = _lane_grid(R, C, lanes, block_c)
     out = pl.pallas_call(
         functools.partial(_update_lanes_kernel, order=m1 - 1),
+        name="taylor_update_lanes",
         grid=grid,
         in_specs=[_SMEM, _row_spec(lanes, block_c, m1),
                   _row_spec(lanes, block_c)],
@@ -285,6 +290,7 @@ def taylor_update_2d(old_diffs: jnp.ndarray, feats: jnp.ndarray, *,
     grid = (R // block_r, C // block_c)
     return pl.pallas_call(
         functools.partial(_update_kernel, order=m1 - 1),
+        name="taylor_update",
         grid=grid,
         in_specs=[
             pl.BlockSpec((m1, block_r, block_c), lambda r, c: (0, r, c)),

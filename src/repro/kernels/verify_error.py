@@ -83,8 +83,11 @@ def _verify_tau_kernel(p_ref, r_ref, tau_ref, o_ref, *, eps: float):
 
 def verify_sums(pred: jnp.ndarray, ref: jnp.ndarray, *,
                 tau: Optional[jnp.ndarray] = None, eps: float = 1e-8,
-                block_c: int = 1024, interpret: bool = False) -> jnp.ndarray:
+                block_c: int = 1024, interpret: bool = False,
+                name: str = "verify_sums") -> jnp.ndarray:
     """One-pass per-sample verification sums. pred/ref [B, N] (N%128==0).
+    ``name`` is the kernel's name in the compiled program and a profile
+    (callers pass their own: ``verify_accept``, ``verify_error``).
 
     Without ``tau``: returns [B, 2] = (Σ(p−r)², Σr²).
     With per-lane thresholds ``tau`` [B]: returns [B, 4] =
@@ -104,6 +107,7 @@ def verify_sums(pred: jnp.ndarray, ref: jnp.ndarray, *,
     if tau is None:
         return pl.pallas_call(
             _verify_kernel,
+            name=name,
             grid=grid,
             in_specs=[tile, tile],
             out_specs=pl.BlockSpec((B, 2), lambda c: (0, 0)),
@@ -114,6 +118,7 @@ def verify_sums(pred: jnp.ndarray, ref: jnp.ndarray, *,
     # against the [B, 1] error column
     return pl.pallas_call(
         functools.partial(_verify_tau_kernel, eps=eps),
+        name=name,
         grid=grid,
         in_specs=[tile, tile, pl.BlockSpec((B, 1), lambda c: (0, 0))],
         out_specs=pl.BlockSpec((B, 4), lambda c: (0, 0)),
@@ -125,5 +130,6 @@ def verify_sums(pred: jnp.ndarray, ref: jnp.ndarray, *,
 def verify_error(pred: jnp.ndarray, ref: jnp.ndarray, *, eps: float = 1e-8,
                  block_c: int = 1024, interpret: bool = False) -> jnp.ndarray:
     """Per-sample relative L2 error (eq. 4). pred/ref [B, N] -> [B]."""
-    sums = verify_sums(pred, ref, block_c=block_c, interpret=interpret)
+    sums = verify_sums(pred, ref, block_c=block_c, interpret=interpret,
+                       name="verify_error")
     return jnp.sqrt(sums[:, 0]) / (jnp.sqrt(sums[:, 1]) + eps)

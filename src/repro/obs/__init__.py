@@ -12,11 +12,18 @@ together (``docs/observability.md``):
   * ``lane_accumulator()`` — factory for per-session on-device counter
                    accumulation that adds zero host syncs.
 
+Beside the bundle, ``span`` opens a named host span on the JAX
+profiler's clock. The engine opens these at its layer boundaries
+whatever ``obs`` is, since they record only while a profiler session
+runs; they name the host's share of a device trace.
+
 The cardinal rule: constructing or enabling observability must never
 change a traced program or add a device sync to the serving path.
-``SpeCaEngine(obs=False)`` contains no observability code path at all
-(pinned bitwise in ``tests/test_obs.py``), and ``obs=True`` only ever
-(a) runs host-side Python, (b) dispatches the async accumulator update.
+``SpeCaEngine(obs=False)`` records no metric, event or trace and keeps
+no per-tick clock stamps; its only observability code is the profiler
+spans (pinned bitwise against ``obs=True`` in ``tests/test_obs.py``),
+and ``obs=True`` only ever (a) runs host-side Python, (b) dispatches the
+async accumulator update.
 """
 from __future__ import annotations
 
@@ -26,12 +33,14 @@ from .clock import Clock, FakeClock, MonotonicClock, resolve_clock
 from .exporters import chrome_trace, prometheus_text, to_jsonl
 from .lane_metrics import DEFAULT_ERR_EDGES, LaneAccumulator
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry, Series)
-from .trace import (FlightRecorder, Span, Timings, Trace, build_trace)
+from .trace import (SCOPE_NAMES, SPAN_NAMES, FlightRecorder, Span, Timings,
+                    Trace, build_trace, span)
 
 __all__ = [
     "Clock", "MonotonicClock", "FakeClock", "resolve_clock",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Series",
     "Timings", "Span", "Trace", "FlightRecorder", "build_trace",
+    "span", "SPAN_NAMES", "SCOPE_NAMES",
     "LaneAccumulator", "DEFAULT_ERR_EDGES",
     "to_jsonl", "prometheus_text", "chrome_trace",
     "Observability",

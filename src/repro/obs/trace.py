@@ -22,6 +22,11 @@ walk a first-class representation:
     ``Trace`` objects, retrievable by ticket
     (``SpeCaEngine.trace(ticket)``). Bounded on purpose: a long-lived
     serving process must never grow host memory with traffic served.
+  * ``span`` — a named host span on the JAX profiler's own clock, so it
+    lands in the same trace as the device's operations. The engine opens
+    one at each layer boundary (``SPAN_NAMES``) whether or not ``obs``
+    is on; with no profiler session running it costs about a
+    microsecond and records nothing.
 
 Everything here is host-side bookkeeping assembled from data the engine
 materialises anyway (the per-tick flag fetch at request completion),
@@ -34,14 +39,39 @@ import dataclasses
 from collections import OrderedDict, deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
+import jax
+
+# The engine's host spans, outermost first (docs/observability.md).
+# ``speca.sync.*`` spans cover only a blocking device-to-host read.
+SPAN_NAMES = ("speca.tick", "speca.admit", "speca.dispatch",
+              "speca.harvest", "speca.release", "speca.sync.flags",
+              "speca.sync.emit", "speca.sync.prefill")
+# The lane step's device scopes (``jax.named_scope`` in
+# ``repro.core.lane_step``); each op's name stack carries them.
+SCOPE_NAMES = ("speca.draft", "speca.verify", "speca.full",
+               "speca.update", "speca.rollback")
+
+
+def span(name: str, **ids: Any) -> jax.profiler.TraceAnnotation:
+    """A host span named ``name`` on the profiler's clock, as a context
+    manager. ``ids`` (ticket, lane, tick) ride as the event's arguments,
+    so the name stays one fixed string that readers match by prefix.
+    Adds no device sync and changes no traced program."""
+    return jax.profiler.TraceAnnotation(name, **ids)
+
 
 @dataclasses.dataclass(frozen=True)
 class Timings:
     """Lifecycle timestamps (engine-clock seconds) and tick indices of
     one request.
 
-    ``first_tick_s`` is None when the request was drained before any
-    scheduler tick dispatched it. Tick indices are the owning session's
+    ``first_token_s`` is the end of the first per-tick flag fetch after
+    admission that shows the request's lane advanced: the moment the
+    host knows its first served step exists on the device. Only lanes
+    that draft deeper than one step (or run the controller) fetch their
+    flags every tick, so it is None for depth-1 lanes, hence for
+    diffusion, and for requests drained before such a fetch; stamping it
+    adds no sync. Tick indices are the owning session's
     scheduler ticks: ``admit_tick`` is the tick the request entered its
     lanes at, ``finish_tick`` the tick after which it completed (equals
     ``Result.finish_tick``).
@@ -50,7 +80,7 @@ class Timings:
     submit_s: float
     admit_s: float
     finish_s: float
-    first_tick_s: Optional[float] = None
+    first_token_s: Optional[float] = None
     submit_tick: int = 0
     admit_tick: int = 0
     finish_tick: int = 0

@@ -86,7 +86,7 @@ from repro.core.forecaster import get_forecaster
 from repro.core.workload import DiffusionWorkload, Workload
 from repro.diffusion.pipeline import null_cond_like
 from repro.obs import (Clock, Observability, Timings, Trace, build_trace,
-                       resolve_clock)
+                       resolve_clock, span)
 from repro.serving.policy import QueueFull, RequestPolicy, Ticket
 from repro.serving.scheduler import (QueueItem, Scheduler, fresh_scheduler,
                                      make_scheduler)
@@ -228,9 +228,9 @@ class _Entry:                          # may span two lanes
     t0: float
     done: int = 0       # host-tracked denoising step counter
     draft_k: int = 1    # the request's draft horizon (policy.draft_depth)
-    # engine-clock stamp of the first scheduler tick that dispatched this
-    # entry (None until then) — Timings.first_tick_s
-    first_tick_s: Optional[float] = None
+    # engine-clock stamp at the end of the first per-tick flag fetch that
+    # showed this entry's lane advanced — Timings.first_token_s
+    first_token_s: Optional[float] = None
 
     @property
     def streams(self) -> int:
@@ -268,11 +268,10 @@ class _Session:
         self._flag_log: List[Optional[Dict[str, Any]]] = []
         self._flag_np: Dict[int, Dict[str, np.ndarray]] = {}
         # host clock stamp at the START of each session tick, index-
-        # aligned with _flag_log (gc'd together): trace spans and
-        # Timings.first_tick_s read these, never the device
+        # aligned with _flag_log; kept only with obs on, for the trace
+        # spans build_trace synthesises
         self._tick_s: List[Optional[float]] = []
-        # device-side telemetry accumulator (None when obs is off: the
-        # obs-off session contains no observability code path at all)
+        # device-side telemetry accumulator (None when obs is off)
         self._acc = engine._obs.lane_accumulator() \
             if engine._obs is not None else None
 
@@ -317,12 +316,13 @@ class _Session:
                         and self.lane_entry[l ^ 1] is not None]
                 free = half or free
             lanes = (free[0],)
-        entry = _Entry(item=item, lanes=lanes, start_tick=self.tick,
-                       t0=self.e.clock.now(),
-                       draft_k=int(item.policy.draft_depth or 1))
-        for l in lanes:
-            self.lane_entry[l] = entry
-        self._fill(entry)
+        with span("speca.admit", ticket=item.ticket_id, lane=lanes[0]):
+            entry = _Entry(item=item, lanes=lanes, start_tick=self.tick,
+                           t0=self.e.clock.now(),
+                           draft_k=int(item.policy.draft_depth or 1))
+            for l in lanes:
+                self.lane_entry[l] = entry
+            self._fill(entry)
         obs = self.e._obs
         if obs is not None:
             obs.recorder.record(
@@ -334,8 +334,9 @@ class _Session:
 
     def _fill(self, entry: _Entry) -> None:
         """Reset the entry's lane slice(s) for its request (host-side;
-        every update is lane-local — on a mesh the SPMD partitioner
-        serves it from the owning shard, the table is never gathered).
+        every update writes one lane, but on a mesh each is an eager
+        scatter at a traced lane index, which the SPMD partitioner
+        compiles to an all-gather of the whole lane-sharded leaf).
         The workload contributes its dynamic payload through
         ``fill_payload`` (diffusion: the seed noise latent; decode: one
         prompt prefill scattered into the lane's cache slice)."""
@@ -406,28 +407,32 @@ class _Session:
         lane moves 0..K steps per tick), so the tick's ``advanced``
         counters are fetched — the one host/device sync deep speculation
         costs. Returns the completions."""
-        now = self.e.clock.now()
-        self._tick_s.append(now)
-        state, flags = self.step_fn(self.state)   # async dispatch
-        self.state = state
-        self._flag_log.append(flags)
-        self.tick += 1
-        if self._acc is not None:
-            # fold this tick's flags into the on-device accumulator —
-            # one extra ASYNC dispatch, zero host syncs
-            self._acc.update(flags)
+        if self.e._obs is not None:
+            self._tick_s.append(self.e.clock.now())
+        with span("speca.dispatch", tick=self.tick):
+            state, flags = self.step_fn(self.state)   # async dispatch
+            self.state = state
+            self._flag_log.append(flags)
+            self.tick += 1
+            if self._acc is not None:
+                # fold this tick's flags into the on-device accumulator —
+                # one extra ASYNC dispatch, zero host syncs
+                self._acc.update(flags)
         # controller entries adapt draft_k ON DEVICE, so their host-side
         # draft_k is only the starting point: treat them as deep (their
         # per-tick advancement is data-dependent like any chain lane)
         deep = any(e.draft_k > 1 or e.item.policy.controller is not None
                    for e in self.entries())
-        adv = self._fetch(self.tick - 1)["advanced"] if deep else None
+        if deep:
+            adv = self._fetch(self.tick - 1)["advanced"]
+            fetched_s = self.e.clock.now()
         completed: List[Tuple[_Entry, Result]] = []
         for entry in self.entries():
-            if entry.first_tick_s is None:
-                entry.first_tick_s = now
             # depth-1 entries advance exactly 1/tick (host-predictable)
-            entry.done += int(adv[entry.lanes[0]]) if deep else 1
+            step = int(adv[entry.lanes[0]]) if deep else 1
+            if deep and step and entry.first_token_s is None:
+                entry.first_token_s = fetched_s
+            entry.done += step
             if entry.done < entry.item.steps:
                 continue
             # request complete: NOW touch the device (sample readback +
@@ -439,20 +444,22 @@ class _Session:
         return completed
 
     def _release(self, entry: _Entry) -> None:
-        st = dict(self.state)
-        for l in entry.lanes:
-            self.lane_entry[l] = None
         lane0, k = entry.lanes[0], entry.streams
-        st["active"] = st["active"].at[lane0:lane0 + k].set(False)
-        if self.paired and entry.streams == 2:
-            st["paired"] = st["paired"].at[lane0:lane0 + 2].set(False)
-        self.state = st
+        with span("speca.release", ticket=entry.item.ticket_id, lane=lane0):
+            st = dict(self.state)
+            for l in entry.lanes:
+                self.lane_entry[l] = None
+            st["active"] = st["active"].at[lane0:lane0 + k].set(False)
+            if self.paired and entry.streams == 2:
+                st["paired"] = st["paired"].at[lane0:lane0 + 2].set(False)
+            self.state = st
 
     def _fetch(self, t: int) -> Dict[str, np.ndarray]:
         if t not in self._flag_np:
-            self._flag_np[t] = {k: np.asarray(v)
-                                for k, v in self._flag_log[t].items()
-                                if k in LS.COUNTER_FLAGS}
+            with span("speca.sync.flags", tick=t):
+                self._flag_np[t] = {k: np.asarray(v)
+                                    for k, v in self._flag_log[t].items()
+                                    if k in LS.COUNTER_FLAGS}
         return self._flag_np[t]
 
     def _gc_flags(self) -> None:
@@ -472,6 +479,12 @@ class _Session:
         partial and full accounting can never diverge. Flags are read at
         the entry's first lane: for a guided pair the flags are
         pair-equal, so this is the pair's single decision."""
+        with span("speca.harvest", ticket=entry.item.ticket_id,
+                  lane=entry.lanes[0]):
+            return self._harvest(entry, end_tick, completed)
+
+    def _harvest(self, entry: _Entry, end_tick: int,
+                 completed: bool) -> Result:
         item = entry.item
         obs = self.e._obs
         lane0, k = entry.lanes[0], entry.streams
@@ -500,7 +513,7 @@ class _Session:
         finish_s = self.e.clock.now()
         timings = Timings(
             submit_s=item.submit_s, admit_s=entry.t0, finish_s=finish_s,
-            first_tick_s=entry.first_tick_s,
+            first_token_s=entry.first_token_s,
             submit_tick=item.submit_tick, admit_tick=entry.start_tick,
             finish_tick=end_tick)
         res = Result(
@@ -745,9 +758,11 @@ class SpeCaEngine:
         # every session's compiled program)
         self.forecaster = get_forecaster(forecaster)
         self.controller = bool(controller)
-        # observability (docs/observability.md): obs=False keeps every
-        # obs code path out of the engine entirely (pinned bitwise in
-        # tests/test_obs.py); obs=True builds a fresh Observability on
+        # observability (docs/observability.md): obs=False records no
+        # metric, event or trace (pinned bitwise against obs=True in
+        # tests/test_obs.py; the profiler spans are always compiled in
+        # and record only under a profiler); obs=True builds a fresh
+        # Observability on
         # the engine clock; a prebuilt Observability is adopted as-is
         # (sharing one registry across engines), and supplies the clock
         # when the caller passed none.
@@ -847,9 +862,11 @@ class SpeCaEngine:
         if key not in self._lane_fns:
             wl = self._workload(tag)
 
-            def run(params, state):
+            def speca_lane_step(params, state):
                 # the weights are the program's argument: traced through
-                # a params-swapped adapter, never embedded as constants
+                # a params-swapped adapter, never embedded as constants.
+                # The function's name is the program's name in a profile
+                # (jit_speca_lane_step), one for every workload and width
                 return LS.build_workload_step(
                     wl.with_params(params), lanes=W,
                     draft_mode=self.draft_mode,
@@ -859,8 +876,8 @@ class SpeCaEngine:
                     forecaster=self.forecaster,
                     controller=self.controller, mesh=self.mesh)(state)
 
-            self._lane_fns[key] = functools.partial(jax.jit(run),
-                                                    wl.params)
+            self._lane_fns[key] = functools.partial(
+                jax.jit(speca_lane_step), wl.params)
             if self._obs is not None:
                 # per-tag program-build count (the compile-cost proxy:
                 # each new (tag, width, mode) key is one XLA program)
@@ -1014,22 +1031,24 @@ class SpeCaEngine:
         for _ in range(n):
             if not self._sessions:
                 break
-            if self._obs is not None:
-                # sample queue state BEFORE admission so burst peaks are
-                # visible — the poll-boundary sampling this replaces saw
-                # the queue only after the tick had drained it
-                self._obs_tick_sample()
-            for _sess, entry in self._admit_into(self._sessions,
-                                                 self._sched):
-                self._ticket_status[entry.item.ticket_id] = "running"
-            busy = [s for s in self._sessions.values() if s.busy()]
-            if not busy:
-                break
-            self._tick_count += 1
-            for sess in busy:
-                for entry, res in sess.advance():
-                    self._record(res)
-                    done.append(res)
+            with span("speca.tick", tick=self._tick_count):
+                if self._obs is not None:
+                    # sample queue state BEFORE admission so burst peaks
+                    # are visible — the poll-boundary sampling this
+                    # replaces saw the queue only after the tick had
+                    # drained it
+                    self._obs_tick_sample()
+                for _sess, entry in self._admit_into(self._sessions,
+                                                     self._sched):
+                    self._ticket_status[entry.item.ticket_id] = "running"
+                busy = [s for s in self._sessions.values() if s.busy()]
+                if not busy:
+                    break
+                self._tick_count += 1
+                for sess in busy:
+                    for entry, res in sess.advance():
+                        self._record(res)
+                        done.append(res)
         return done
 
     def _obs_tick_sample(self) -> None:

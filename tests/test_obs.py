@@ -184,7 +184,7 @@ def test_jsonl_roundtrip():
 
 def test_chrome_trace_document():
     t = Timings(submit_s=1.0, admit_s=2.0, finish_s=5.0,
-                first_tick_s=2.5, submit_tick=0, admit_tick=3,
+                first_token_s=2.5, submit_tick=0, admit_tick=3,
                 finish_tick=6)
     tr = build_trace(ticket_id=9, request_id=4, workload="diffusion",
                      tenant="gold", completed=True, timings=t,
@@ -444,12 +444,57 @@ def test_fake_clock_timings_deterministic(tiny_trained_dit):
     assert t1 == t2
     for t in t1:
         assert t.submit_s <= t.admit_s <= t.finish_s
-        assert t.first_tick_s is not None \
-            and t.admit_s <= t.first_tick_s <= t.finish_s
+        # depth-1 lanes fetch no per-tick flags: no first-token stamp
+        assert t.first_token_s is None
         assert t.queue_wait_s == pytest.approx(t.admit_s - t.submit_s)
         assert t.service_s == pytest.approx(t.finish_s - t.admit_s)
         assert t.total_s == pytest.approx(t.finish_s - t.submit_s)
         assert t.service_ticks == t.finish_tick - t.admit_tick > 0
+
+
+@pytest.mark.parametrize("obs", [False, True])
+def test_first_token_stamp(tiny_trained_dit, obs):
+    """A deep-drafting decode request's first_token_s is the end of the
+    first per-tick flag fetch that shows its lane advanced: after
+    admission, before finish, and with or without obs. The per-tick
+    clock stamps exist only for the obs trace."""
+    eng = _make_engine(tiny_trained_dit, workload="decode", K=3, obs=obs,
+                       clock=FakeClock(0.0, auto_tick=0.5))
+    vocab = _decode_workloads()[0].vocab_size
+    res = _drive(eng, _decode_requests(3, 3, vocab))
+    for r in res:
+        t = r.timings
+        assert t.first_token_s is not None
+        assert t.admit_s < t.first_token_s < t.finish_s
+    assert bool(eng._sessions["decode"]._tick_s) == obs
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("workload,K", [("diffusion", 1), ("diffusion", 3),
+                                        ("decode", 1), ("decode", 3)])
+def test_lane_step_scopes_in_op_metadata(tiny_trained_dit, workload, K):
+    """Every phase scope of the lane step (``step`` at depth 1,
+    ``chain_step`` deeper) is in the op_name metadata of the compiled
+    program, which is named ``jit_speca_lane_step``."""
+    from repro.core import lane_step as LS
+    from repro.obs import SCOPE_NAMES
+    eng = _make_engine(tiny_trained_dit, workload=workload, K=K)
+    eng.start(workload=workload)
+    sess = eng._sessions[workload]
+    wl = eng.workloads[workload]
+    cond = {"labels": jnp.zeros((1,), jnp.int32)} if wl.cond_in_state \
+        else {}
+    state = LS.init_workload_state(
+        wl, sess.W, cond, guidance="mixed" if sess.paired else False)
+    step = sess.step_fn
+    text = step.func.lower(*step.args, state).compile().as_text()
+    assert text.startswith("HloModule jit_speca_lane_step")
+    want = [s for s in SCOPE_NAMES if K > 1 or s != "speca.rollback"]
+    for scope in want:
+        assert f"/{scope}/" in text, f"no op under {scope!r}"
+    if K == 1:
+        assert "/speca.rollback/" not in text
+    eng.shutdown()
 
 
 def test_engine_trace_spans(tiny_trained_dit):

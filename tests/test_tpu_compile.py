@@ -5,7 +5,10 @@ that the chip's compiler accepts them at the widths the server runs:
 block shapes Mosaic's tiling rule admits, SMEM scalars, scoped VMEM.
 Nothing runs — each kernel is lowered and compiled for one device of a
 described ``v5e:2x2`` topology, and its HLO must hold a
-``tpu_custom_call`` (a Mosaic kernel, not the interpreter's jnp).
+``tpu_custom_call`` (a Mosaic kernel, not the interpreter's jnp) under
+the kernel's fixed name, the name a device profile shows. The engine's
+lane step, compiled the same way at a tiny size, must keep its program
+name, its kernels' names and its phase scopes.
 
 Widths: DiT-XL/2 at 256² (28 layers, 2 branches, 4 lanes, 256 tokens,
 d 1152, bf16 tables, Taylor order 2), mamba2-130m decode state
@@ -17,8 +20,11 @@ only one process at a time may load the TPU library), and the
 persistent compilation cache is off around these compiles, since a
 compile for a described device cannot be read back without one.
 """
+import dataclasses
 import functools
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -119,12 +125,86 @@ CASES = {
 }
 
 
+# each case's kernel name in the compiled program (the pallas_call's
+# ``name``), which the benchmark's trace readers match by prefix
+KERNEL_NAMES = {
+    "predict_lanes": "taylor_predict_lanes",
+    "predict_chain": "taylor_predict_chain_lanes",
+    "update_lanes": "taylor_update_lanes",
+    "spectral_update": "spectral_update_lanes",
+    "rollback_latent": "lane_rollback",
+    "rollback_ssm_state": "lane_rollback",
+    "rollback_conv_state": "lane_rollback",
+    "update_lanes_widest_tile": "taylor_update_lanes",
+    "predict_chain_widest_tile": "taylor_predict_chain_lanes",
+    "verify_accept": "verify_accept",
+    "verify_accept_mixed": "verify_accept_mixed",
+}
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, mosaic):
     fn, args, static = CASES[name]
     compiled = _compile(fn, one_chip, *args, **static)
+    text = compiled.as_text()
+    assert f"%{KERNEL_NAMES[name]}." in text, \
+        f"no kernel named {KERNEL_NAMES[name]!r} in the program"
     mem = compiled.memory_analysis()
     if mem is not None:
         used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
         assert used < 16 * 2**30, used
+
+
+@pytest.fixture
+def fresh_traces():
+    """The lane step calls the module-level jitted kernel wrappers, whose
+    traces are cached by shape: clear them around a Mosaic compile so it
+    neither reuses a CPU-interpreter trace nor leaves a Mosaic one for
+    the CPU tests at the same tiny shapes."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_lane_step_names_for_v5e(depth, one_chip, mosaic, fresh_traces):
+    """A tiny DiT engine's lane step (depth 1: ``step``; depth 2:
+    ``chain_step``) compiled for the chip: the program is
+    ``jit_speca_lane_step``, its kernels keep their names, and every op
+    of a phase carries the phase's scope in its ``op_name``."""
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    import chip_smoke
+    from repro.configs import (DiffusionConfig, SpeCaConfig, get_config,
+                               reduced)
+    from repro.core import lane_step as LS
+    from repro.serving import SpeCaEngine
+    cfg = dataclasses.replace(reduced(get_config("dit-xl2")), num_layers=2,
+                              d_model=64, d_ff=128, num_heads=4,
+                              num_kv_heads=4, num_classes=8)
+    dcfg = DiffusionConfig(num_inference_steps=10, latent_size=8)
+    eng = SpeCaEngine(cfg, chip_smoke.dit_params(cfg, 0), dcfg,
+                      SpeCaConfig(), verify_backend="fused", lanes=4,
+                      max_draft_depth=depth)
+    eng.start()
+    sess = eng._sessions["diffusion"]
+    state = LS.init_workload_state(
+        eng.workloads["diffusion"], sess.W,
+        {"labels": jnp.zeros((1,), jnp.int32)}, guidance="mixed")
+    step = sess.step_fn
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (*step.args, state))
+    text = step.func.lower(*shapes).compile().as_text()
+    assert text.startswith("HloModule jit_speca_lane_step")
+    predict = "taylor_predict_lanes" if depth == 1 \
+        else "taylor_predict_chain_lanes"
+    kernels = [predict, "taylor_update_lanes", "verify_accept_mixed"]
+    scopes = ["speca.draft", "speca.verify", "speca.full", "speca.update"]
+    if depth > 1:
+        kernels.append("lane_rollback")
+        scopes.append("speca.rollback")
+    for k in kernels:
+        assert f"%{k}." in text, f"no kernel named {k!r}"
+    for sc in scopes:
+        assert f"/{sc}/" in text, f"no op under the {sc!r} scope"
