@@ -120,25 +120,30 @@ def init_controller_state(lanes: int, order: int) -> Dict[str, Any]:
     for them — value-identical to the controller-free program.
     """
     W = lanes
-    zf = jnp.zeros((W,), jnp.float32)
+
+    def zf():
+        # a buffer per key: the engine donates the state, and one buffer
+        # under two keys cannot be donated twice
+        return jnp.zeros((W,), jnp.float32)
+
     return {
         "ctl_on": jnp.zeros((W,), bool),
         "ctl_dl": jnp.zeros((W,), bool),
-        "ctl_rate": zf,
-        "ctl_adv": zf,
-        "ctl_target": zf,
-        "ctl_gain": zf,
-        "ctl_ema": zf,
-        "ctl_tau_lo": zf,
-        "ctl_tau_hi": zf,
-        "ctl_tau_base": zf,
+        "ctl_rate": zf(),
+        "ctl_adv": zf(),
+        "ctl_target": zf(),
+        "ctl_gain": zf(),
+        "ctl_ema": zf(),
+        "ctl_tau_lo": zf(),
+        "ctl_tau_hi": zf(),
+        "ctl_tau_base": zf(),
         "ctl_k_lo": jnp.ones((W,), jnp.int32),
         "ctl_k_hi": jnp.ones((W,), jnp.int32),
         "ctl_order": jnp.full((W,), int(order), jnp.int32),
         "ctl_order_lo": jnp.full((W,), int(order), jnp.int32),
         "ctl_order_hi": jnp.full((W,), int(order), jnp.int32),
         "ctl_ticks": jnp.zeros((W,), jnp.int32),
-        "ctl_deadline": zf,
+        "ctl_deadline": zf(),
     }
 
 

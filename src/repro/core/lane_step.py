@@ -283,7 +283,9 @@ def init_workload_state(wl, lanes: int, cond_template: Dict[str, Any], *,
     tstate = fc.init_state(wl.scfg.taylor_order, feat_shape,
                            wl.table_dtype, lanes=W)
     if wl.cond_in_state:
-        cond = {k: jnp.broadcast_to(jnp.asarray(v), (W,) + jnp.shape(v)[1:])
+        # copies, never the caller's arrays: the engine donates the state
+        cond = {k: jnp.array(jnp.broadcast_to(jnp.asarray(v),
+                                              (W,) + jnp.shape(v)[1:]))
                 for k, v in cond_template.items()}
     else:
         cond = {}
@@ -334,6 +336,89 @@ def init_lane_state(cfg: ModelConfig, dcfg: DiffusionConfig,
     wl = DiffusionWorkload(cfg, params=None, dcfg=dcfg, scfg=scfg)
     return init_workload_state(wl, lanes, cond_template, x=x,
                                active=active, guidance=guidance, mesh=mesh)
+
+
+# What every filled lane starts from besides its request's own values: an
+# empty table with fresh anchor metadata, step 0, active.
+LANE_START = {"diffs": 0, "n_anchors": 0, "anchor_step": -1, "gap": 1.0,
+              "since": 0, "step": 0, "active": True}
+
+
+def write_lanes(state: Dict[str, Any], lane, rows: Dict[str, Any], *,
+                count: int = 1,
+                mesh: Optional[Any] = None) -> Dict[str, Any]:
+    """Write ``rows`` into lanes ``lane .. lane + count - 1`` of ``state``.
+
+    Traceable: ``lane`` may be a traced scalar, so one compiled program
+    writes any lane. Each row is broadcast to its leaf's lane block (the
+    leaf's shape with its lane axis, ``repro.sharding.specs.
+    LANE_STATE_AXES``, cut to ``count``) and cast to the leaf's dtype —
+    the values ``state[key].at[lane].set(row)`` writes. ``rows["cond"]``
+    is a dict over the conditioning keys (lane axis 0). Leaves without a
+    row pass through.
+
+    Each write is a ``dynamic_update_slice`` on the lane axis: jitted
+    with the state donated it runs in place, at the cost of the block's
+    bytes. With ``mesh`` (a lane-sharded state) the writes run in a
+    ``shard_map`` over the lane axis, the rows entering replicated: each
+    shard rewrites one block of its own lanes, with the new rows where
+    the lane is its own and its current contents elsewhere, so no
+    collective is emitted. (An eager ``.at[lane].set`` at a traced index
+    gathers the whole leaf; left to the partitioner, a row computed in
+    the program, such as the seed's noise, is computed sharded and
+    all-reduced.) The block must lie within one shard, which the lane
+    width rules guarantee for a lane and for a pair slot.
+    """
+    from repro.sharding import specs as SH
+    paths = [(k,) for k in rows if k != "cond"] \
+        + [("cond", k) for k in rows.get("cond", {})]
+
+    def at(tree, path):
+        for p in path:
+            tree = tree[p]
+        return tree
+
+    leaves = [at(state, p) for p in paths]
+    vals = [jnp.asarray(at(rows, p)) for p in paths]
+    axes = [0 if p[0] == "cond" else SH.LANE_STATE_AXES[p[0]]
+            for p in paths]
+
+    def put(leaves, vals, start, own):
+        out = []
+        for leaf, val, ax in zip(leaves, vals, axes):
+            shape = leaf.shape[:ax] + (count,) + leaf.shape[ax + 1:]
+            block = jnp.broadcast_to(val.astype(leaf.dtype), shape)
+            if own is not None:
+                block = jnp.where(own, block, jax.lax.dynamic_slice_in_dim(
+                    leaf, start, count, ax))
+            out.append(jax.lax.dynamic_update_slice_in_dim(
+                leaf, block, start, ax))
+        return out
+
+    lane = jnp.asarray(lane, jnp.int32)
+    if mesh is None:
+        new = put(leaves, vals, lane, None)
+    else:
+        def local(leaves, vals, lane):
+            n = leaves[0].shape[axes[0]]        # lanes on this shard
+            base = jax.lax.axis_index(SH.LANE_AXIS) * n
+            own = (lane >= base) & (lane < base + n)
+            return put(leaves, vals,
+                       jnp.clip(lane - base, 0, n - count), own)
+
+        P = jax.sharding.PartitionSpec
+        specs = [SH.lane_spec(leaf.ndim, ax)
+                 for leaf, ax in zip(leaves, axes)]
+        new = jax.shard_map(local, mesh=mesh,
+                            in_specs=(specs, [P()] * len(vals), P()),
+                            out_specs=specs, check_vma=False)(
+                                leaves, vals, lane)
+    out = dict(state)
+    if "cond" in rows:
+        out["cond"] = dict(state["cond"])
+    for path, leaf in zip(paths, new):
+        at(out, path[:-1])[path[-1]] = leaf
+    return out
 
 
 def build_workload_step(wl, *, lanes: int, draft_mode: str = "taylor",

@@ -43,10 +43,13 @@ Two workloads ship:
     guidance is a diffusion concept, guided decode requests are rejected
     at policy resolution.
 
-Host-side hooks (``fill_payload`` / ``emit``) keep the engine's
-host/device discipline: filling a decode lane runs ONE prefill forward
-for the request's prompt and scatters the resulting cache into the
-lane's slice; harvesting reads back the emitted token row.
+Filling a lane is split in two halves so the engine admits with one
+compiled program and no host read: ``fill_inputs`` (host) only
+dispatches and returns the request's device or host values — the noise
+seed for diffusion, ONE prompt prefill's logits and cache for decode —
+and ``fill_rows`` (traced, inside the engine's lane-fill program) turns
+them into the lane's payload rows. Harvesting (``emit``) reads back the
+latent or the emitted token row.
 """
 from __future__ import annotations
 
@@ -64,6 +67,7 @@ from repro.core.complexity import (decode_forward_flops, decode_verify_flops,
 from repro.core.lane_step import num_tokens as _diff_num_tokens
 from repro.core.lane_step import table_dtype as _table_dtype
 from repro.core.lane_step import verify_layer as _verify_layer
+from repro.core.lane_step import write_lanes
 from repro.diffusion.pipeline import latent_shape, make_stepper, model_inputs
 from repro.layers import blocks as blk
 from repro.layers import model as M
@@ -111,9 +115,10 @@ class Workload:
 
     Traced hooks (called inside the jitted step): ``t_frac``,
     ``step_context``, ``spec_forward``, ``full_forward``, ``zero_out``,
-    ``select_out``, ``advance``, ``rollback``. Host hooks (engine
-    validate / fill / harvest): ``validate_request``, ``init_payload``,
-    ``fill_payload``, ``emit``.
+    ``select_out``, ``advance``, ``rollback``; ``fill_rows`` runs inside
+    the engine's lane-fill program. Host hooks (engine validate / fill /
+    harvest): ``validate_request``, ``init_payload``, ``fill_inputs``,
+    ``emit``.
     """
 
     tag: str = "?"
@@ -174,15 +179,31 @@ class Workload:
         (raise ``ValueError``). Called by the engine BEFORE any side
         effect of admission — session start, ticket issue, queue push —
         so a bad request (e.g. a malformed decode prompt) fails the
-        ``submit()`` itself instead of blowing up ``fill_payload``
+        ``submit()`` itself instead of blowing up ``fill_inputs``
         mid-tick inside a live session. Default: accept everything."""
 
     def init_payload(self, lanes: int, *, x=None) -> Dict[str, Any]:
         raise NotImplementedError
 
+    def fill_inputs(self, request, steps: int) -> Dict[str, Any]:
+        """Host half of a lane fill: the request's payload inputs as
+        device or host arrays. Dispatches only — never reads the device
+        back."""
+        raise NotImplementedError
+
+    def fill_rows(self, state: Dict[str, Any],
+                  inputs: Dict[str, Any]) -> Dict[str, Any]:
+        """Traced half of a lane fill: the payload rows of one lane from
+        ``fill_inputs``'s values, keyed by state key, for
+        ``lane_step.write_lanes`` (``state`` gives the leaf shapes)."""
+        raise NotImplementedError
+
     def fill_payload(self, state: Dict[str, Any], lane: int, request,
                      steps: int) -> Dict[str, Any]:
-        raise NotImplementedError
+        """Both halves at once, outside the engine: ``state`` with lane
+        ``lane``'s payload filled for ``request``."""
+        return write_lanes(state, lane, self.fill_rows(
+            state, self.fill_inputs(request, steps)))
 
     def emit(self, state: Dict[str, Any], lane: int, done: int):
         raise NotImplementedError
@@ -258,16 +279,17 @@ class DiffusionWorkload(Workload):
                           jnp.float32)
         return {"x": x}
 
-    def fill_payload(self, state, lane, request, steps):
-        # both lanes of a guided pair call this with the SAME request, so
-        # recomputing the noise per lane keeps the pair's latent rows
-        # identical (PRNGKey(seed) is deterministic)
-        noise = jax.random.normal(jax.random.PRNGKey(request.seed),
-                                  latent_shape(self.cfg, self.dcfg, 1),
-                                  jnp.float32)
-        state = dict(state)
-        state["x"] = state["x"].at[lane].set(noise[0])
-        return state
+    def fill_inputs(self, request, steps):
+        # PRNGKey of a Python int keeps its low 32 bits: the same key,
+        # built inside the program from this scalar
+        return {"seed": np.uint32(request.seed & 0xFFFFFFFF)}
+
+    def fill_rows(self, state, inputs):
+        # one noise row; both lanes of a guided pair get it (the pair's
+        # latent rows are identical)
+        return {"x": jax.random.normal(jax.random.PRNGKey(inputs["seed"]),
+                                       latent_shape(self.cfg, self.dcfg, 1),
+                                       jnp.float32)}
 
     def emit(self, state, lane, done):
         with span("speca.sync.emit", lane=lane):
@@ -391,7 +413,7 @@ class DecodeWorkload(Workload):
     def _prompt_of(self, request, steps) -> np.ndarray:
         """The request's normalised [1, P] prompt, or ``ValueError``
         when malformed / too long for the lane cache — shared by
-        ``validate_request`` (submit time) and ``fill_payload``
+        ``validate_request`` (submit time) and ``fill_inputs``
         (admission time) so the two can never disagree."""
         try:
             prompt = np.asarray(request.cond["tokens"], np.int32)
@@ -413,26 +435,25 @@ class DecodeWorkload(Workload):
     def validate_request(self, request, steps):
         self._prompt_of(request, steps)
 
-    def fill_payload(self, state, lane, request, steps):
+    def fill_inputs(self, request, steps):
         prompt = self._prompt_of(request, steps)
-        P = prompt.shape[1]
         logits, cache = self._prefill(self.params, jnp.asarray(prompt))
-        with span("speca.sync.prefill", lane=lane):
-            tok0 = int(np.argmax(np.asarray(jax.device_get(logits))[0]))
-        state = dict(state)
+        return {"logits": logits, "cache": cache,
+                "pos0": np.int32(prompt.shape[1])}
+
+    def fill_rows(self, state, inputs):
+        # the first token is taken on the device: no host read of logits
+        rows = {"tok": jnp.argmax(inputs["logits"][0]).astype(jnp.int32),
+                "tokens": 0, "pos0": inputs["pos0"]}
         for key in self._cache_keys:
-            # clear the lane's slice (previous occupant), then scatter the
-            # prefix (on a mesh each eager scatter at a traced lane index
-            # gathers the whole lane-sharded leaf first)
-            cleared = state[key].at[:, lane].set(0)
+            c = inputs["cache"][key]
             if key in ("k", "v"):
-                state[key] = cleared.at[:, lane, :P].set(cache[key][:, 0])
-            else:
-                state[key] = cleared.at[:, lane].set(cache[key][:, 0])
-        state["tok"] = state["tok"].at[lane, 0].set(tok0)
-        state["tokens"] = state["tokens"].at[lane].set(0)
-        state["pos0"] = state["pos0"].at[lane].set(P)
-        return state
+                # the prompt's P positions, zeros up to the lane's S
+                pad = [(0, 0)] * c.ndim
+                pad[2] = (0, state[key].shape[2] - c.shape[2])
+                c = jnp.pad(c, pad)
+            rows[key] = c
+        return rows
 
     def emit(self, state, lane, done):
         with span("speca.sync.emit", lane=lane):

@@ -45,7 +45,7 @@ import jax
 # ``speca.sync.*`` spans cover only a blocking device-to-host read.
 SPAN_NAMES = ("speca.tick", "speca.admit", "speca.dispatch",
               "speca.harvest", "speca.release", "speca.sync.flags",
-              "speca.sync.emit", "speca.sync.prefill")
+              "speca.sync.emit")
 # The lane step's device scopes (``jax.named_scope`` in
 # ``repro.core.lane_step``); each op's name stack carries them.
 SCOPE_NAMES = ("speca.draft", "speca.verify", "speca.full",

@@ -77,6 +77,7 @@ import math
 from typing import Any, Dict, Iterator, List, Optional, Tuple, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import DiffusionConfig, ModelConfig, SpeCaConfig
@@ -333,13 +334,12 @@ class _Session:
         return entry
 
     def _fill(self, entry: _Entry) -> None:
-        """Reset the entry's lane slice(s) for its request (host-side;
-        every update writes one lane, but on a mesh each is an eager
-        scatter at a traced lane index, which the SPMD partitioner
-        compiles to an all-gather of the whole lane-sharded leaf).
-        The workload contributes its dynamic payload through
-        ``fill_payload`` (diffusion: the seed noise latent; decode: one
-        prompt prefill scattered into the lane's cache slice)."""
+        """Reset the entry's lane slice(s) for its request with ONE
+        dispatch of the engine's lane-fill program (``speca_lane_fill``:
+        donated state, traced lane index, no host read back). The host
+        half gathers the lane's values; the workload contributes its
+        payload inputs through ``fill_inputs`` (diffusion: the noise
+        seed; decode: one prompt prefill's logits and cache)."""
         e, wl = self.e, self.wl
         req, pol = entry.item.request, entry.item.policy
         if self.state is None:
@@ -349,54 +349,35 @@ class _Session:
                 forecaster=e.forecaster, controller=e.controller,
                 mesh=e.mesh)
         tau0 = float(wl.scfg.tau0 if pol.tau0 is None else pol.tau0)
-        lane0 = entry.lanes[0]
+        k = entry.streams
         # draft_k is pair-equal by construction: a guided pair drafts
         # pair-coherently, one chain decision per position (docs/cfg.md)
-        self._fill_lane(lane0, req.cond, tau0, entry)
-        if entry.streams == 2:
-            nc = pol.negative_cond
-            if nc is None:
-                nc = e.null_cond if e.null_cond is not None \
-                    else null_cond_like(wl.cfg, req.cond)
-            self._fill_lane(lane0 + 1, nc, tau0, entry)
-            gs = float(pol.guidance_scale)
-            st = dict(self.state)
-            st["gscale"] = st["gscale"].at[lane0:lane0 + 2].set(gs)
-            st["paired"] = st["paired"].at[lane0:lane0 + 2].set(True)
-            self.state = st
-        elif self.paired:
-            st = dict(self.state)
-            st["paired"] = st["paired"].at[lane0].set(False)
-            self.state = st
-
-    def _fill_lane(self, lane: int, cond: Dict[str, Any], tau0: float,
-                   entry: _Entry) -> None:
-        wl = self.wl
-        state = dict(self.state)
-        state["draft_k"] = state["draft_k"].at[lane].set(entry.draft_k)
-        state["max_step"] = state["max_step"].at[lane].set(
-            entry.item.steps)
-        state["diffs"] = state["diffs"].at[:, :, :, lane].set(0.0)
-        state["n_anchors"] = state["n_anchors"].at[lane].set(0)
-        state["anchor_step"] = state["anchor_step"].at[lane].set(-1)
-        state["gap"] = state["gap"].at[lane].set(1.0)
-        state["since"] = state["since"].at[lane].set(0)
-        state["step"] = state["step"].at[lane].set(0)
-        state["active"] = state["active"].at[lane].set(True)
-        state["tau0"] = state["tau0"].at[lane].set(tau0)
-        if self.e.controller:
+        rows = {"draft_k": np.full(k, entry.draft_k, np.int32),
+                "max_step": np.full(k, entry.item.steps, np.int32),
+                "tau0": np.full(k, tau0, np.float32)}
+        if e.controller:
             # closed-loop lanes start at the request's resolved knobs;
             # controller-free lanes get the all-off row (bitwise inert)
-            cv = CT.lane_values(entry.item.policy.controller, tau0=tau0,
+            cv = CT.lane_values(pol.controller, tau0=tau0,
                                 order=wl.scfg.taylor_order,
-                                max_draft_depth=self.e.max_draft_depth)
-            for ck, cval in cv.items():
-                state[ck] = state[ck].at[lane].set(cval)
+                                max_draft_depth=e.max_draft_depth)
+            rows.update({ck: np.full(k, v, self.state[ck].dtype)
+                         for ck, v in cv.items()})
+        if self.paired:
+            rows["paired"] = np.full(k, k == 2)
+            if k == 2:
+                rows["gscale"] = np.full(k, float(pol.guidance_scale),
+                                         np.float32)
+        conds: Tuple[Any, ...] = ()
         if wl.cond_in_state:
-            state["cond"] = {k: v.at[lane].set(cond[k][0])
-                             for k, v in state["cond"].items()}
-        self.state = wl.fill_payload(state, lane, entry.item.request,
-                                     entry.item.steps)
+            conds = (req.cond,)
+            if k == 2:
+                # None: the program builds the null conditioning
+                conds += (pol.negative_cond if pol.negative_cond
+                          is not None else e.null_cond,)
+        self.state = e._fill_fns[wl.tag](
+            self.state, np.int32(entry.lanes[0]), rows, conds,
+            wl.fill_inputs(req, entry.item.steps))
 
     # --- advance ---------------------------------------------------------
     def advance(self) -> List[Tuple[_Entry, Result]]:
@@ -446,13 +427,13 @@ class _Session:
     def _release(self, entry: _Entry) -> None:
         lane0, k = entry.lanes[0], entry.streams
         with span("speca.release", ticket=entry.item.ticket_id, lane=lane0):
-            st = dict(self.state)
             for l in entry.lanes:
                 self.lane_entry[l] = None
-            st["active"] = st["active"].at[lane0:lane0 + k].set(False)
-            if self.paired and entry.streams == 2:
-                st["paired"] = st["paired"].at[lane0:lane0 + 2].set(False)
-            self.state = st
+            rows = {"active": np.zeros(k, bool)}
+            if self.paired and k == 2:
+                rows["paired"] = np.zeros(k, bool)
+            self.state = speca_lane_release(self.state, np.int32(lane0),
+                                            rows, mesh=self.e.mesh)
 
     def _fetch(self, t: int) -> Dict[str, np.ndarray]:
         if t not in self._flag_np:
@@ -586,6 +567,15 @@ class _Session:
                                             completed=False)))
             self._release(entry)
         return out
+
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnames="mesh")
+def speca_lane_release(state, lane, rows, mesh=None):
+    """Free lanes ``lane ..`` (the extent of ``rows``): write ``rows``
+    (``active`` off, and ``paired`` off for a guided pair) into the
+    donated state in one program, ``jit_speca_lane_release``."""
+    return LS.write_lanes(state, lane, rows, count=rows["active"].shape[0],
+                          mesh=mesh)
 
 
 def _dropped_result(item: QueueItem) -> Result:
@@ -782,6 +772,8 @@ class SpeCaEngine:
         self._full_flops = diff.full_flops if diff is not None else 0.0
         self._verify_flops = diff.verify_flops if diff is not None else 0.0
         self._lane_fns: Dict[Tuple[str, int, Any], Any] = {}
+        self._fill_fns = {tag: self._lane_fill(wl)
+                          for tag, wl in self.workloads.items()}
         # lifecycle state (shared long-lived sessions, one per workload
         # tag; serve_batched uses private per-call sessions instead)
         self._sessions: Dict[str, _Session] = {}
@@ -887,6 +879,33 @@ class SpeCaEngine:
                     "compile", self.clock.now(), workload=tag,
                     width=W, mode=str(mode))
         return self._lane_fns[key]
+
+    def _lane_fill(self, wl: Workload):
+        """The jitted lane fill of one workload: ``(state, lane, rows,
+        conds, inputs) -> state``. It writes lanes ``lane ..`` (one, or a
+        guided pair: the extent of ``rows``) with every value a fresh
+        lane holds — ``LS.LANE_START``, the host's per-lane ``rows``, the
+        conditioning rows ``conds`` and the workload's payload rows from
+        ``inputs`` — in one program that donates the state and takes the
+        lane as a traced scalar, so it compiles once per session shape
+        (and per prompt length where a decode cache holds K/V) and runs
+        in place (``LS.write_lanes``; on a lane mesh, shard-
+        local). Its name in a profile is ``jit_speca_lane_fill``."""
+        mesh = self.mesh
+
+        def speca_lane_fill(state, lane, rows, conds, inputs):
+            rows = {**LS.LANE_START, **rows, **wl.fill_rows(state, inputs)}
+            if conds:
+                if conds[-1] is None:
+                    conds = conds[:-1] + (null_cond_like(wl.cfg, conds[0]),)
+                rows["cond"] = {
+                    key: jnp.concatenate([jnp.asarray(c[key])[:1].astype(
+                        leaf.dtype) for c in conds])
+                    for key, leaf in state["cond"].items()}
+            return LS.write_lanes(state, lane, rows,
+                                  count=rows["tau0"].shape[0], mesh=mesh)
+
+        return jax.jit(speca_lane_fill, donate_argnums=0)
 
     def lane_width(self, lanes: int, n_requests: int) -> int:
         """Effective lane width the scheduler will actually serve at:

@@ -215,9 +215,9 @@ def replicated(mesh: Mesh) -> NamedSharding:
 #   model params           | (tree)              | P() — replicated
 #
 # The table is the big operand: lane-sharding it means a D-device engine
-# holds 1/D of the table per device and the refill path (host-side
-# ``.at[lane].set``) is a lane-local dynamic-update-slice that the SPMD
-# partitioner serves from the owning shard — the table is never gathered.
+# holds 1/D of the table per device, and the refill path
+# (``lane_step.write_lanes`` in a ``shard_map``) rewrites a lane block on
+# each shard — the table is never gathered.
 #
 # CFG pair rule: a guided request occupies the lane PAIR (2k, 2k+1) —
 # cond and uncond streams. The guided combination ``u + s·(c − u)`` and
@@ -248,7 +248,7 @@ LANE_AXIS = "data"
 # the KV/SSM caches are laid out [L, W, ...] so their lane axis is 1 —
 # lane-sharding them is exactly the "decode state sharded like the
 # table" rule: each shard owns its lanes' cache slices, and the fill
-# path's lane-local scatter never gathers the cache.
+# path's shard-local write never gathers the cache.
 LANE_STATE_AXES = {
     "x": 0, "since": 0, "step": 0, "active": 0,
     "diffs": 3, "n_anchors": 0, "anchor_step": 0, "gap": 0,

@@ -208,3 +208,97 @@ def test_lane_step_names_for_v5e(depth, one_chip, mosaic, fresh_traces):
         assert f"%{k}." in text, f"no kernel named {k!r}"
     for sc in scopes:
         assert f"/{sc}/" in text, f"no op under the {sc!r} scope"
+
+
+def _dit_xl2_fill(mesh=None, lanes=LANES):
+    """The DiT-XL/2 engine's lane fill and release (the programs every
+    admission and completion runs) with the shapes of their arguments:
+    the 256² session state of ``lanes`` lanes and one unguided lane's
+    values. The fill uses no weights, so the engine is built without."""
+    from repro.configs import DiffusionConfig, SpeCaConfig, get_config
+    from repro.core import lane_step as LS
+    from repro.serving import SpeCaEngine
+    dcfg = DiffusionConfig(latent_size=32, schedule="cosine")
+    eng = SpeCaEngine(get_config("dit-xl2"), None, dcfg,
+                      SpeCaConfig(taylor_order=2), lanes=lanes, mesh=mesh)
+    wl = eng.workloads["diffusion"]
+    state = jax.eval_shape(lambda: LS.init_workload_state(
+        wl, lanes, {"labels": jnp.zeros((1,), jnp.int32)},
+        guidance="mixed"))
+    rows = {"draft_k": jax.ShapeDtypeStruct((1,), I32),
+            "max_step": jax.ShapeDtypeStruct((1,), I32),
+            "tau0": jax.ShapeDtypeStruct((1,), F32),
+            "paired": jax.ShapeDtypeStruct((1,), BOOL)}
+    args = (jax.ShapeDtypeStruct((), I32), rows,
+            ({"labels": jax.ShapeDtypeStruct((1,), I32)},),
+            {"seed": jax.ShapeDtypeStruct((), jnp.uint32)})
+    return eng, state, args
+
+
+def _placed(tree, sharding):
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding(x) if callable(sharding)
+        else sharding), tree)
+
+
+def test_lane_fill_in_place_for_v5e(one_chip):
+    """The one-chip DiT-XL/2 lane fill compiles for the chip as
+    ``jit_speca_lane_fill`` and writes the donated state in place: every
+    leaf is aliased from input to output, and the program needs no
+    scratch anywhere near the size of the difference table."""
+    eng, state, args = _dit_xl2_fill()
+    fill = eng._fill_fns["diffusion"]
+    compiled = fill.lower(_placed(state, one_chip),
+                          *_placed(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_speca_lane_fill")
+    assert "input_output_alias" in text
+    n_leaves = len(jax.tree.leaves(state))
+    assert text.count("may-alias") + text.count("must-alias") == n_leaves
+    table = state["diffs"]
+    assert table.shape == (M1,) + DIT_FEAT
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        table_bytes = table.size * table.dtype.itemsize
+        assert mem.temp_size_in_bytes < table_bytes // 16, \
+            mem.temp_size_in_bytes
+
+
+def test_lane_fill_on_lane_mesh_no_collectives_for_v5e(topo):
+    """On a lane mesh over the four chips of a described v5e:2x2 (8
+    DiT-XL/2 lanes per chip), the lane fill and release write each
+    shard's own lanes: no all-gather, all-reduce, collective-permute or
+    all-to-all in either program, and the table stays aliased."""
+    import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.serving.engine import speca_lane_release
+    from repro.sharding import specs as SH
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        eng, state, args = _dit_xl2_fill(mesh=mesh, lanes=8 * 4)
+        shardings = SH.lane_state_shardings(mesh, state)
+        state = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            state, shardings)
+        rep = NamedSharding(mesh, PartitionSpec())
+        fill = eng._fill_fns["diffusion"].lower(
+            state, *_placed(args, rep)).compile().as_text()
+        release = speca_lane_release.lower(
+            state, jax.ShapeDtypeStruct((), I32, sharding=rep),
+            {"active": jax.ShapeDtypeStruct((2,), BOOL, sharding=rep),
+             "paired": jax.ShapeDtypeStruct((2,), BOOL, sharding=rep)},
+            mesh=mesh).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    assert fill.startswith("HloModule jit_speca_lane_fill")
+    assert release.startswith("HloModule jit_speca_lane_release")
+    for text in (fill, release):
+        for op in ("all-gather", "all-reduce", "collective-permute",
+                   "all-to-all"):
+            assert op not in text, op
+        assert "input_output_alias" in text
